@@ -1,5 +1,8 @@
 import os
 
+# a host-device study: pin to the CPU so that, on a machine with a chip, the
+# child never reaches for the accelerator the parent process holds
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 """Child process for bench_memory: lowers a reduced llama prefill on an

@@ -53,11 +53,15 @@ _AGG_J = {"sum": jnp.sum, "max": jnp.max, "min": jnp.min, "prod": jnp.prod}
 
 def lower_einsum(spec: EinSpec, *args):
     """One EinSum node -> jnp.  Contractions go straight to jnp.einsum (XLA
-    dot_general -> MXU); general (⊗,⊕) nodes lower to broadcast + reduce."""
-    if spec.is_contraction and len(spec.in_labels) == 2:
-        return jnp.einsum(spec.einsum_str(), *args)
-    if spec.is_contraction and len(spec.in_labels) == 1 and spec.combine == "id":
-        return jnp.einsum(spec.einsum_str(), *args)
+    dot_general -> MXU; float32 operands at float32 precision, see
+    ``kernels.tiling.mxu_precision``); general (⊗,⊕) nodes lower to
+    broadcast + reduce."""
+    if spec.is_contraction and (len(spec.in_labels) == 2 or (
+            len(spec.in_labels) == 1 and spec.combine == "id")):
+        from repro.kernels.tiling import mxu_precision
+
+        return jnp.einsum(spec.einsum_str(), *args,
+                          precision=mxu_precision(*(a.dtype for a in args)))
 
     all_labels = spec.all_labels
 

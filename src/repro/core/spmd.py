@@ -1137,17 +1137,11 @@ def _pspec(layout: Layout):
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """Version-portable shard_map with replication checking off (manual
-    axis_index slicing defeats the rep checker by design)."""
-    try:
-        from jax.experimental.shard_map import shard_map
+    """``jax.shard_map`` with varying-axis checking off (manual
+    axis_index slicing defeats the checker by design)."""
+    import jax
 
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except (ImportError, TypeError):  # pragma: no cover - newer jax
-        from jax import shard_map
-
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
 
 

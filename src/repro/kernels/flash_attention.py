@@ -14,21 +14,24 @@ Online-softmax attention with explicit VMEM tiling:
 * block sizes default to (128, 128) — MXU-aligned (multiples of 128 in the
   contracting and lane dims) and small enough that the working set
   q(128·d) + k,v(128·d each) + acc(128·d) fits VMEM for d ≤ 256.
+* a sequence that is not a multiple of its block is zero-padded up to one:
+  padded query rows are sliced off the output and padded keys get zero
+  weight, so any prompt length runs the same kernel.
 
 Numerics: scores and the running state are f32 regardless of input dtype
-(bf16 in production); the output is cast back.
+(bf16 in production); the output is cast back.  Float32 inputs take
+float32 dots (``tiling.mxu_precision``), not the MXU's one bfloat16 pass.
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import COMPILER_PARAMS as _COMPILER_PARAMS
+from repro.kernels.tiling import fit, mxu_precision, pad_to
 
 NEG_INF = -1e30
 
@@ -45,6 +48,8 @@ def _flash_kernel(
     blk_k: int,
     q_offset: int,
     kv_offset: int,
+    kv_len: int,
+    padded: bool,
 ):
     iq = pl.program_id(2)
     ik = pl.program_id(3)
@@ -72,26 +77,21 @@ def _flash_kernel(
         k = k_ref[0, 0].astype(jnp.float32)                  # (blk_k, d)
         s = jax.lax.dot_general(                              # (blk_q, blk_k) on MXU
             q, k, (((1,), (1,)), ((), ())),
+            precision=mxu_precision(q_ref.dtype, k_ref.dtype),
             preferred_element_type=jnp.float32)
 
-        if causal or window:
-            qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-            kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-            mask = jnp.ones((blk_q, blk_k), dtype=jnp.bool_)
-            if causal:
-                mask &= kpos <= qpos
-            if window:
-                mask &= kpos > qpos - window
-            s = jnp.where(mask, s, NEG_INF)
-
+        s = _mask_scores(s, q_start, k_start, causal, window)
         m_prev = m_ref[:, 0]                                  # (blk_q,)
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
         alpha = jnp.exp(m_prev - m_cur)
         p = jnp.exp(s - m_cur[:, None])                       # (blk_q, blk_k)
+        if padded:
+            p = _zero_padded_keys(p, ik * blk_k, kv_len)
         l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
         v = v_ref[0, 0].astype(jnp.float32)                   # (blk_k, d)
         pv = jax.lax.dot_general(                              # MXU
             p, v, (((1,), (0,)), ((), ())),
+            precision=mxu_precision(v_ref.dtype),
             preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
         m_ref[:, 0] = m_cur
@@ -122,16 +122,18 @@ def flash_attention(
     assert hq % hkv == 0, "GQA requires hq % hkv == 0"
     group = hq // hkv
     scale = (d ** -0.5) if scale is None else float(scale)
-    blk_q = min(blk_q, sq)
-    blk_k = min(blk_k, sk)
-    assert sq % blk_q == 0 and sk % blk_k == 0, "seq must divide block"
-    grid = (b, hq, sq // blk_q, sk // blk_k)
+    blk_q, sqp = fit(sq, blk_q)
+    blk_k, skp = fit(sk, blk_k)
+    q = pad_to(q, 2, sqp)
+    k, v = pad_to(k, 2, skp), pad_to(v, 2, skp)
+    grid = (b, hq, sqp // blk_q, skp // blk_k)
 
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, window=window,
-        blk_q=blk_q, blk_k=blk_k, q_offset=q_offset, kv_offset=kv_offset)
+        blk_q=blk_q, blk_k=blk_k, q_offset=q_offset, kv_offset=kv_offset,
+        kv_len=sk, padded=skp != sk)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -143,16 +145,39 @@ def flash_attention(
         ],
         out_specs=pl.BlockSpec((1, 1, blk_q, d),
                                lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq, sqp, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((blk_q, 1), jnp.float32),   # m
             pltpu.VMEM((blk_q, 1), jnp.float32),   # l
             pltpu.VMEM((blk_q, d), jnp.float32),   # acc
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
+    return out[:, :, :sq]
+
+
+def _mask_scores(s, q_start, k_start, causal: bool, window: int):
+    """Causal / sliding-window mask of one (blk_q, blk_k) score tile at
+    absolute positions ``q_start`` / ``k_start``."""
+    if not (causal or window):
+        return s
+    qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    mask = jnp.ones(s.shape, dtype=jnp.bool_)
+    if causal:
+        mask &= kpos <= qpos
+    if window:
+        mask &= kpos > qpos - window
+    return jnp.where(mask, s, NEG_INF)
+
+
+def _zero_padded_keys(p, k_first: int, kv_len: int):
+    """Zero the softmax weights of the padded keys (array index >=
+    ``kv_len``) in a tile whose first key has index ``k_first``."""
+    kidx = k_first + jax.lax.broadcasted_iota(jnp.int32, p.shape, 1)
+    return jnp.where(kidx < kv_len, p, 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -172,6 +197,8 @@ def _flash_step_kernel(
     window: int,
     blk_q: int,
     blk_k: int,
+    kv_len: int,
+    padded: bool,
 ):
     iq = pl.program_id(2)
     ik = pl.program_id(3)
@@ -179,8 +206,8 @@ def _flash_step_kernel(
 
     @pl.when(ik == 0)
     def _init():
-        m_s[...] = m_in_ref[0, 0][:, None]
-        l_s[...] = l_in_ref[0, 0][:, None]
+        m_s[...] = m_in_ref[0, 0]
+        l_s[...] = l_in_ref[0, 0]
         acc_s[...] = acc_in_ref[0, 0]
 
     q_start = iq * blk_q + offs_ref[0, 0]
@@ -193,34 +220,29 @@ def _flash_step_kernel(
     k = k_ref[0, 0].astype(jnp.float32)                    # (blk_k, d)
     s = jax.lax.dot_general(                               # (blk_q, blk_k)
         q, k, (((1,), (1,)), ((), ())),
+        precision=mxu_precision(q_ref.dtype, k_ref.dtype),
         preferred_element_type=jnp.float32)
-
-    if causal or window:
-        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-        mask = jnp.ones((blk_q, blk_k), dtype=jnp.bool_)
-        if causal:
-            mask &= kpos <= qpos
-        if window:
-            mask &= kpos > qpos - window
-        s = jnp.where(mask, s, NEG_INF)
+    s = _mask_scores(s, q_start, k_start, causal, window)
 
     m_prev = m_s[:, 0]
     m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
     alpha = jnp.exp(m_prev - m_cur)
     p = jnp.exp(s - m_cur[:, None])
+    if padded:
+        p = _zero_padded_keys(p, ik * blk_k, kv_len)
     l_s[:, 0] = l_s[:, 0] * alpha + jnp.sum(p, axis=1)
     v = v_ref[0, 0].astype(jnp.float32)
     pv = jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())),
+        precision=mxu_precision(v_ref.dtype),
         preferred_element_type=jnp.float32)
     acc_s[...] = acc_s[...] * alpha[:, None] + pv
     m_s[:, 0] = m_cur
 
     @pl.when(ik == nk - 1)
     def _fin():
-        m_out_ref[0, 0] = m_s[:, 0]
-        l_out_ref[0, 0] = l_s[:, 0]
+        m_out_ref[0, 0] = m_s[...]
+        l_out_ref[0, 0] = l_s[...]
         acc_out_ref[0, 0] = acc_s[...]
 
 
@@ -247,16 +269,20 @@ def flash_attention_step(
     / sliding-window masks compare against the block's *absolute* positions,
     which is what keeps rotated kv blocks correctly masked at every ring
     offset.  Finalize with ``ref.attention_finalize`` (acc / l).
+
+    The carried ``m`` / ``l`` cross the kernel boundary as ``(b, hq, sq,
+    1)`` columns: their ``(blk_q, 1)`` blocks meet the TPU's block rule
+    (second-minor a multiple of 8, minor the whole dim), which ``(1, 1,
+    blk_q)`` blocks over ``(b, hq, sq)`` do not.
     """
     b, hq, sq, d = q.shape
     _, hkv, sk, _ = k.shape
     assert hq % hkv == 0, "GQA requires hq % hkv == 0"
     group = hq // hkv
     scale = (d ** -0.5) if scale is None else float(scale)
-    blk_q = min(blk_q, sq)
-    blk_k = min(blk_k, sk)
-    assert sq % blk_q == 0 and sk % blk_k == 0, "seq must divide block"
-    grid = (b, hq, sq // blk_q, sk // blk_k)
+    blk_q, sqp = fit(sq, blk_q)
+    blk_k, skp = fit(sk, blk_k)
+    grid = (b, hq, sqp // blk_q, skp // blk_k)
 
     if carry is None:
         m = jnp.full((b, hq, sq), NEG_INF, jnp.float32)
@@ -264,14 +290,21 @@ def flash_attention_step(
         acc = jnp.zeros((b, hq, sq, d), jnp.float32)
     else:
         m, l, acc = carry
+    q = pad_to(q, 2, sqp)
+    k, v = pad_to(k, 2, skp), pad_to(v, 2, skp)
+    m = pad_to(m, 2, sqp, NEG_INF)[..., None]
+    l = pad_to(l, 2, sqp)[..., None]
+    acc = pad_to(acc, 2, sqp)
     offs = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                       jnp.asarray(kv_offset, jnp.int32)]).reshape(1, 2)
 
     kernel = functools.partial(
         _flash_step_kernel, scale=scale, causal=causal, window=window,
-        blk_q=blk_q, blk_k=blk_k)
+        blk_q=blk_q, blk_k=blk_k, kv_len=sk, padded=skp != sk)
 
-    return pl.pallas_call(
+    state_spec = pl.BlockSpec((1, 1, blk_q, 1),
+                              lambda ib, ih, iq, ik: (ib, ih, iq, 0))
+    m, l, acc = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -281,19 +314,17 @@ def flash_attention_step(
                          lambda ib, ih, iq, ik: (ib, ih // group, ik, 0)),
             pl.BlockSpec((1, 1, blk_k, d),
                          lambda ib, ih, iq, ik: (ib, ih // group, ik, 0)),
-            pl.BlockSpec((1, 1, blk_q), lambda ib, ih, iq, ik: (ib, ih, iq)),
-            pl.BlockSpec((1, 1, blk_q), lambda ib, ih, iq, ik: (ib, ih, iq)),
+            state_spec, state_spec,
             pl.BlockSpec((1, 1, blk_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, blk_q), lambda ib, ih, iq, ik: (ib, ih, iq)),
-            pl.BlockSpec((1, 1, blk_q), lambda ib, ih, iq, ik: (ib, ih, iq)),
+            state_spec, state_spec,
             pl.BlockSpec((1, 1, blk_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, sq, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, sqp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, sqp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, sqp, d), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_q, 1), jnp.float32),   # m
@@ -304,7 +335,8 @@ def flash_attention_step(
         # input buffer to its output so XLA updates the ring state in place
         # instead of allocating fresh HBM every ring step
         input_output_aliases={4: 0, 5: 1, 6: 2},
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(offs, q, k, v, m, l, acc)
+    return m[:, :, :sq, 0], l[:, :, :sq, 0], acc[:, :, :sq]

@@ -10,14 +10,12 @@ every matmul dim is MXU-aligned and the working set
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import COMPILER_PARAMS as _COMPILER_PARAMS
+from repro.kernels.tiling import fit, mxu_precision, pad_to
 
 
 def _mm_kernel(x_ref, w_ref, o_ref, acc_ref):
@@ -32,6 +30,7 @@ def _mm_kernel(x_ref, w_ref, o_ref, acc_ref):
         x_ref[...].astype(jnp.float32),
         w_ref[...].astype(jnp.float32),
         (((1,), (0,)), ((), ())),
+        precision=mxu_precision(x_ref.dtype, w_ref.dtype),
         preferred_element_type=jnp.float32)
 
     @pl.when(ik == nk - 1)
@@ -50,21 +49,26 @@ def matmul(
 ) -> jnp.ndarray:
     m, k = x.shape
     k2, n = w.shape
-    assert k == k2
-    blk_m, blk_n, blk_k = min(blk_m, m), min(blk_n, n), min(blk_k, k)
-    assert m % blk_m == 0 and n % blk_n == 0 and k % blk_k == 0
+    if k != k2:
+        raise ValueError(f"matmul: contraction dims differ, {k} vs {k2}")
+    blk_m, mp = fit(m, blk_m)
+    blk_n, np_ = fit(n, blk_n)
+    blk_k, kp = fit(k, blk_k)
+    x = pad_to(pad_to(x, 0, mp), 1, kp)
+    w = pad_to(pad_to(w, 0, kp), 1, np_)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _mm_kernel,
-        grid=(m // blk_m, n // blk_n, k // blk_k),
+        grid=(mp // blk_m, np_ // blk_n, kp // blk_k),
         in_specs=[
             pl.BlockSpec((blk_m, blk_k), lambda im, jn, ik: (im, ik)),
             pl.BlockSpec((blk_k, blk_n), lambda im, jn, ik: (ik, jn)),
         ],
         out_specs=pl.BlockSpec((blk_m, blk_n), lambda im, jn, ik: (im, jn)),
-        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((blk_m, blk_n), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w)
+    return out[:m, :n]

@@ -2,26 +2,15 @@
 
 Functions (not module constants) so importing never touches jax device
 state; the dry-run sets XLA_FLAGS before any jax import.
-
-``AxisType`` only exists in newer jax releases; on older installs we fall
-back to plain meshes (every axis behaves as the legacy default), keeping the
-module importable — and the test suite collectable — everywhere.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.4.38
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

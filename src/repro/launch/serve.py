@@ -23,6 +23,7 @@ import numpy as np
 from repro.configs import get_config, reduced
 from repro.configs.base import ShapeConfig
 from repro.launch import steps
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, mesh_axes_dict
 from repro.models import transformer as tf
 from repro.models.attention import KVCache
@@ -150,6 +151,7 @@ def serve(cfg, prompts: np.ndarray, *, max_new: int = 32, mesh=None,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama-7b")
     ap.add_argument("--reduced", action="store_true")

@@ -28,6 +28,7 @@ from repro.configs import get_config, reduced
 from repro.configs.base import ShapeConfig
 from repro.data.synthetic import SyntheticLM, batch_shardings
 from repro.launch import steps
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, mesh_axes_dict
 from repro.models import transformer as tf
 from repro.models.eingraphs import fsdp_axes_for, program_for
@@ -145,6 +146,7 @@ def _print_pipeline_summary(cfg, shape: ShapeConfig, intra_axes: dict,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama-7b")
     ap.add_argument("--reduced", action="store_true",

@@ -19,6 +19,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.tiling import pad_to
 from repro.models.common import ParamFactory
 
 
@@ -74,9 +75,14 @@ def ssm_forward(p: dict, x: jnp.ndarray, cfg, *, chunk: int = 256
     xin = jax.nn.silu(xin)
 
     chunk = min(chunk, s)
-    assert s % chunk == 0
-    nchunks = s // chunk
+    nchunks = -(-s // chunk)
+    sp = nchunks * chunk
     decay, drive, C = _ssm_features(p, xin, n)
+    # a length that is not a multiple of the chunk runs identity steps
+    # (decay 1, drive 0) after its last position: every real output and
+    # the final state are exactly those of the unpadded recurrence
+    decay = pad_to(decay, 1, sp, 1.0)
+    drive, C = pad_to(drive, 1, sp), pad_to(C, 1, sp)
     # reshape to (nchunks, b, chunk, ...)
     def split(t):
         return t.reshape(b, nchunks, chunk, *t.shape[2:]).swapaxes(0, 1)
@@ -97,7 +103,7 @@ def ssm_forward(p: dict, x: jnp.ndarray, cfg, *, chunk: int = 256
 
     h0 = jnp.zeros((b, di, n), jnp.float32)
     h_last, ys = jax.lax.scan(chunk_step, h0, (decay_c, drive_c, C_c))
-    y = ys.swapaxes(0, 1).reshape(b, s, di)
+    y = ys.swapaxes(0, 1).reshape(b, sp, di)[:, :s]
     y = y + xin.astype(jnp.float32) * p["d_skip"].astype(jnp.float32)
     y = (y.astype(x.dtype)) * jax.nn.silu(z)
     out = jnp.einsum("bsd,de->bse", y, p["out_proj"])

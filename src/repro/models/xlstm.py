@@ -20,6 +20,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.tiling import pad_to
 from repro.models.common import ParamFactory, rmsnorm
 
 
@@ -69,16 +70,21 @@ def mlstm_forward(p: dict, x: jnp.ndarray, cfg, *, chunk: int = 256
     logf = -jax.nn.softplus(-f_pre)                           # log sigmoid(f)
 
     chunk = min(chunk, s)
-    assert s % chunk == 0
-    nc = s // chunk
+    nc = -(-s // chunk)
+    sp = nc * chunk
 
-    def split(t, axis=2):
+    def split(t, value=0.0):
+        # a length that is not a multiple of the chunk runs identity steps
+        # after its last position (forget gate 1, input gate ~0, zero q/k/v):
+        # every real output and the final state are those of the unpadded
+        # recurrence
+        t = pad_to(t, 2, sp, value)
         shp = list(t.shape)
-        shp[axis:axis + 1] = [nc, chunk]
-        return jnp.moveaxis(t.reshape(shp), axis, 0)
+        shp[2:3] = [nc, chunk]
+        return jnp.moveaxis(t.reshape(shp), 2, 0)
 
     qc, kc, vc = split(q), split(k), split(v)
-    ic, fc = split(i_pre), split(logf)
+    ic, fc = split(i_pre, -1e30), split(logf)
 
     def chunk_step(carry, inp):
         C, N, M = carry                                       # (b,h,d,d),(b,h,d),(b,h)
@@ -117,7 +123,7 @@ def mlstm_forward(p: dict, x: jnp.ndarray, cfg, *, chunk: int = 256
     N0 = jnp.zeros((b, H, dh), jnp.float32)
     M0 = jnp.full((b, H), -jnp.inf)
     (C, N, M), hs = jax.lax.scan(chunk_step, (C0, N0, M0), (qc, kc, vc, ic, fc))
-    h = jnp.moveaxis(hs, 0, 2).reshape(b, H, s, dh)           # (b,h,s,dh)
+    h = jnp.moveaxis(hs, 0, 2).reshape(b, H, sp, dh)[:, :, :s]  # (b,h,s,dh)
     h = h.transpose(0, 2, 1, 3).reshape(b, s, D).astype(x.dtype)
     h = rmsnorm(h, p["norm"])
     out = jnp.einsum("bsd,de->bse", h * jax.nn.silu(z), p["w_down"])

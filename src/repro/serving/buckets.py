@@ -170,7 +170,7 @@ class BucketRegistry:
 
             logits, caches = base(params, tokens, caches, tables, pos)
             tok = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
-            return tok, caches
+            return tok, caches, jnp.all(jnp.isfinite(logits))
 
         # donate the caches: the pool is the dominant buffer and strictly
         # carried step-to-step

@@ -12,7 +12,9 @@ requests join and leave mid-flight without any recompilation.
 
 Generated tokens stay on device (the decode step argmaxes inside the jit
 and the per-step token vectors are simply accumulated); the host fetches
-everything once at drain, so the loop never forces a per-token sync.
+everything once at drain, so the loop never forces a per-token sync.  Each
+prefill and decode step also leaves a device-side "all logits finite" flag,
+reduced once at drain into ``ServeMetrics.logits_finite``.
 Length-based eviction is the default; passing ``eos_id`` enables early
 exit at the cost of one host sync per step (documented, opt-in).
 """
@@ -64,6 +66,7 @@ class ServeMetrics:
     tokens_generated: int = 0
     t_total_s: float = 0.0
     t_prefill_s: float = 0.0
+    logits_finite: bool = True    # every prefill/decode logit was finite
 
     @property
     def tok_per_s(self) -> float:
@@ -85,6 +88,7 @@ class ServeMetrics:
                             if self.ttft_s else 0.0),
             "t_total_s": self.t_total_s,
             "t_prefill_s": self.t_prefill_s,
+            "logits_finite": self.logits_finite,
         }
 
 
@@ -149,6 +153,7 @@ class ServingEngine:
         self._done: list[Request] = []
         self._next_rid = 0
         self._step_log: list = []     # per-step (batch, 1) device tokens
+        self._finite_log: list = []   # per-step device "logits finite" flags
         self.metrics = ServeMetrics()
 
     # -- API ------------------------------------------------------------------
@@ -212,6 +217,7 @@ class ServingEngine:
         logits, pre_caches = ent.step(self.params, {"tokens": padded},
                                       jnp.int32(plen - 1))
         tok0 = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)  # (1,)
+        self._finite_log.append(jnp.all(jnp.isfinite(logits)))
         # TTFT is defined at the first token's availability: sync here (one
         # per request, not per step)
         req.first_tok = int(jax.device_get(tok0)[0])
@@ -234,11 +240,12 @@ class ServingEngine:
             self._evict(req)
 
     def _decode_phase(self):
-        tok, self.caches = self._decode(
+        tok, self.caches, finite = self._decode(
             self.params, self.tokens, self.caches,
             jnp.asarray(self.tables), jnp.asarray(self.pos))
         self.tokens = tok
         self._step_log.append(tok)
+        self._finite_log.append(finite)
         self.metrics.decode_steps += 1
         eos_row = (np.asarray(tok)[:, 0]
                    if self.eos_id is not None else None)  # opt-in sync
@@ -273,6 +280,10 @@ class ServingEngine:
                 np.int32)
             self.metrics.tokens_generated += len(gen)
             out[req.rid] = gen
+        if self._finite_log:
+            self.metrics.logits_finite &= bool(
+                jnp.all(jnp.stack(self._finite_log)))
         self._step_log.clear()
+        self._finite_log.clear()
         self._done.clear()
         return out

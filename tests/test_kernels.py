@@ -26,6 +26,11 @@ ATT_CASES = [
     (1, 8, 1, 128, 128, 128, True, 0, jnp.float32),
     (1, 4, 4, 128, 128, 64, True, 0, jnp.bfloat16),
     (2, 4, 2, 64, 64, 16, True, 32, jnp.float32),
+    # lengths that are not a block multiple: padded inside the kernel entry
+    (1, 5, 1, 200, 200, 64, True, 0, jnp.float32),
+    (1, 5, 1, 200, 200, 64, True, 64, jnp.float32),
+    (1, 5, 1, 72, 200, 64, False, 0, jnp.float32),
+    (1, 5, 1, 100, 100, 64, True, 0, jnp.float32),
 ]
 
 
@@ -62,6 +67,7 @@ def test_flash_attention_block_shape_invariance():
     (256, 384, 128, jnp.float32),
     (128, 256, 512, jnp.bfloat16),
     (64, 64, 64, jnp.float32),
+    (200, 1600, 200, jnp.float32),   # ragged: every dim padded to 128s
 ])
 def test_matmul_vs_oracle(m, k, n, dt):
     x = _rand((m, k), dt)
@@ -78,6 +84,7 @@ def test_matmul_vs_oracle(m, k, n, dt):
     (4, 128, 256, 128, jnp.float32),
     (8, 128, 128, 384, jnp.float32),
     (2, 256, 128, 128, jnp.bfloat16),
+    (2, 200, 160, 300, jnp.float32),  # ragged capacity, k and n
 ])
 def test_gmm_vs_oracle(e, c, k, n, dt):
     x = _rand((e, c, k), dt)

@@ -112,3 +112,47 @@ def test_dryrun_leaves_env_alone_after_jax_initialized():
         "print(os.environ.get('XLA_FLAGS', '<unset>'))\n",
         {})
     assert out == "<unset>"
+
+
+# ---------------------------------------------------------------------------
+# compile cache: placed from outside, or at one fixed path in the checkout
+# ---------------------------------------------------------------------------
+
+
+def test_compile_cache_env_var_is_honoured(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, the helper sets no directory of
+    its own and compiled programs land in the named one."""
+    body = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro.launch.compile_cache import enable_compile_cache\n"
+        f"assert enable_compile_cache() == {str(tmp_path)!r}\n"
+        f"assert jax.config.jax_compilation_cache_dir == {str(tmp_path)!r}\n"
+        "jax.jit(lambda x: jnp.sin(x) @ x.T)(jnp.ones((64, 64))).block_until_ready()\n"
+    )
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0",
+               JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(os.path.dirname(os.path.dirname(
+                   os.path.abspath(__file__))), "src"))
+    proc = subprocess.run([sys.executable, "-c", body], capture_output=True,
+                          text=True, env=env, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert any(tmp_path.iterdir()), "no compiled program was cached"
+
+
+def test_compile_cache_defaults_to_fixed_checkout_path(monkeypatch):
+    import jax
+
+    from repro.launch import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = compile_cache.enable_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert got == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+        assert compile_cache.enable_compile_cache() == got  # stable path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

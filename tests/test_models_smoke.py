@@ -125,3 +125,29 @@ def test_param_labels_cover_params():
         flat_l = jax.tree.leaves(labels)
         for sds, lab in zip(flat_p, flat_l):
             assert len(lab.split()) == len(sds.shape), (arch, lab, sds.shape)
+
+
+@pytest.mark.parametrize("arch,kind", [("hymba-1.5b", "ssm"),
+                                       ("xlstm-125m", "mlstm")])
+def test_chunked_scan_pads_ragged_lengths(arch, kind):
+    """A length that is not a multiple of the scan chunk (200 rows, chunks
+    of 64) runs identity steps after its end: outputs and final state equal
+    the one-chunk scan of the same 200 rows."""
+    from repro.models import ssm, xlstm
+    from repro.models.common import ParamFactory
+
+    cfg = reduced(get_config(arch))
+    pf = ParamFactory(jax.random.PRNGKey(0), jnp.float32, False)
+    if kind == "ssm":
+        p, fwd = ssm.init_ssm(pf, cfg), ssm.ssm_forward
+    else:
+        p, fwd = xlstm.init_mlstm(pf, cfg), xlstm.mlstm_forward
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 200, cfg.d_model))
+    y, st = fwd(p, x, cfg, chunk=64)
+    y1, st1 = fwd(p, x, cfg, chunk=200)
+    assert y.shape == x.shape
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y1),
+                               rtol=1e-4, atol=1e-5)
+    for a, b in zip(st, st1):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-6)

@@ -89,8 +89,13 @@ def test_ring_chain_matches_dense_every_offset(causal, window, hq, hkv, r):
                                    err_msg=f"ring offset {start}")
 
 
+@pytest.mark.parametrize("blk_q,blk_k", [(16, 8), (24, 6)],
+                         ids=["aligned", "padded"])
 @pytest.mark.parametrize("causal,window,hq,hkv", RING_CONFIGS[:3])
-def test_pallas_step_kernel_matches_ref_chain(causal, window, hq, hkv):
+def test_pallas_step_kernel_matches_ref_chain(causal, window, hq, hkv,
+                                              blk_q, blk_k):
+    """(24, 6) blocks do not divide the 32-row q or the 8-row kv blocks:
+    the kernel entry pads both, and padded keys must get zero weight."""
     from repro.kernels.flash_attention import flash_attention_step
 
     q, k, v = _qkv(hq, hkv)
@@ -103,7 +108,7 @@ def test_pallas_step_kernel_matches_ref_chain(causal, window, hq, hkv):
             q, k[:, :, j * blk:(j + 1) * blk],
             v[:, :, j * blk:(j + 1) * blk], carry,
             causal=causal, window=window, kv_offset=j * blk,
-            blk_q=16, blk_k=8)
+            blk_q=blk_q, blk_k=blk_k)
     got = np.asarray(ref.attention_finalize(carry, q.dtype))
     np.testing.assert_allclose(got, dense, rtol=1e-5, atol=1e-6)
 

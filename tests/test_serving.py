@@ -218,6 +218,7 @@ def test_engine_matches_sequential_serve_bitwise():
     assert metrics.prefills == 3
     assert metrics.tokens_generated == 3 * max_new
     assert len(metrics.ttft_s) == 3
+    assert metrics.logits_finite and metrics.summary()["logits_finite"]
 
     for rid, p in zip(rids, prompts):
         gen, _ = serve(cfg, p[None, :], max_new=max_new, params=params,
