@@ -135,16 +135,17 @@ def serve(cfg, prompts: np.ndarray, *, max_new: int = 32, mesh=None,
     decode = jax.jit(steps.make_serve_step(cfg, policy=policy, mesh=mesh),
                      donate_argnums=(2,))
 
-    t0 = time.time()
+    t0 = time.perf_counter()
     logits, caches = prefill(params, {"tokens": jnp.asarray(prompts)})
     caches = prepare_decode_caches(cfg, caches, prompt_len, kv_len)
-    t_prefill = time.time() - t0
+    logits.block_until_ready()
+    t_prefill = time.perf_counter() - t0
 
     tok = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
-    t0 = time.time()
+    t0 = time.perf_counter()
     gen, caches, decode_steps = decode_loop(decode, params, caches, tok,
                                             prompt_len, max_new)
-    t_decode = time.time() - t0
+    t_decode = time.perf_counter() - t0
     return gen, {"t_prefill_s": t_prefill, "t_decode_s": t_decode,
                  "decode_steps": decode_steps,
                  "tok_per_s": b * decode_steps / max(t_decode, 1e-9)}

@@ -17,6 +17,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels import ops
 from repro.models.common import ParamFactory, apply_rope
 
@@ -150,25 +151,32 @@ def attention_decode_paged(p: dict, x: jnp.ndarray, pool: PagedKVCache,
     """
     blk = pool.k.shape[1]
     W = tables.shape[1]
-    q, k_new, v_new = _project_qkv(p, x, cfg, pos[:, None])
-    blk_ids = jnp.take_along_axis(tables, (pos // blk)[:, None], axis=1)[:, 0]
-    off = pos % blk
-    # slots own disjoint blocks (block 0 = shared scratch for idle slots)
-    k_pool = pool.k.at[blk_ids, off].set(k_new[:, 0])
-    v_pool = pool.v.at[blk_ids, off].set(v_new[:, 0])
+    # obs.part names the step's parts (repro.obs.PARTS) in the compiled
+    # program's metadata, so a device trace can be split by part
+    with obs.part("qkv"):
+        q, k_new, v_new = _project_qkv(p, x, cfg, pos[:, None])
+    with obs.part("kv_write"):
+        blk_ids = jnp.take_along_axis(tables, (pos // blk)[:, None],
+                                      axis=1)[:, 0]
+        off = pos % blk
+        # slots own disjoint blocks (block 0 = shared scratch for idle slots)
+        k_pool = pool.k.at[blk_ids, off].set(k_new[:, 0])
+        v_pool = pool.v.at[blk_ids, off].set(v_new[:, 0])
 
-    kh = ops.kv_block_gather(k_pool, tables, W * blk)   # (b, kv, t, d)
-    vh = ops.kv_block_gather(v_pool, tables, W * blk)
+    with obs.part("kv_gather"):
+        kh = ops.kv_block_gather(k_pool, tables, W * blk)   # (b, kv, t, d)
+        vh = ops.kv_block_gather(v_pool, tables, W * blk)
     qh = q.transpose(0, 2, 1, 3)                        # (b, h, 1, hd)
 
-    idx = jnp.arange(W * blk)
-    valid = idx[None, :] <= pos[:, None]
-    if cfg.window:
-        valid &= idx[None, :] > (pos[:, None] - cfg.window)
-
-    o = _decode_attend(qh, kh, vh, valid, cfg)
-    o = o.transpose(0, 2, 1, 3)
-    out = jnp.einsum("bshd,hda->bsa", o, p["wo"])
+    with obs.part("attend"):
+        idx = jnp.arange(W * blk)
+        valid = idx[None, :] <= pos[:, None]
+        if cfg.window:
+            valid &= idx[None, :] > (pos[:, None] - cfg.window)
+        o = _decode_attend(qh, kh, vh, valid, cfg)
+    with obs.part("out_proj"):
+        o = o.transpose(0, 2, 1, 3)
+        out = jnp.einsum("bshd,hda->bsa", o, p["wo"])
     return out, PagedKVCache(k_pool, v_pool)
 
 

@@ -18,6 +18,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models import attention as attn_mod
 from repro.models import ffn as ffn_mod
 from repro.models import moe as moe_mod
@@ -412,22 +413,26 @@ def _block_decode_paged(blk: str, p: dict, x, cache, tables, pos, cfg,
             p["attn"], h, cache, tables, pos, cfg)
         x = x + a_out
         h2 = rmsnorm(x, p["norm2"], cfg.norm_eps)
-        if cfg.moe:
-            m_out, _ = moe_mod.moe_ffn(p["moe"], h2, cfg, policy=policy,
-                                       mesh=mesh)
-        else:
-            m_out = ffn_mod.ffn(p["ffn"], h2, cfg)
+        with obs.part("ffn"):
+            if cfg.moe:
+                m_out, _ = moe_mod.moe_ffn(p["moe"], h2, cfg, policy=policy,
+                                           mesh=mesh)
+            else:
+                m_out = ffn_mod.ffn(p["ffn"], h2, cfg)
         x = x + m_out
     elif blk == "hymba":
         kv, st = cache
         a_out, kv2 = attn_mod.attention_decode_paged(
             p["attn"], h, kv, tables, pos, cfg)
-        s_out, st2 = ssm_mod.ssm_decode(p["ssm"], h, st, cfg)
+        with obs.part("ssm"):
+            s_out, st2 = ssm_mod.ssm_decode(p["ssm"], h, st, cfg)
         mixed = 0.5 * (rmsnorm(a_out, p["norm_a"], cfg.norm_eps)
                        + rmsnorm(s_out, p["norm_s"], cfg.norm_eps))
         x = x + mixed
         h2 = rmsnorm(x, p["norm2"], cfg.norm_eps)
-        x = x + ffn_mod.ffn(p["ffn"], h2, cfg)
+        with obs.part("ffn"):
+            f_out = ffn_mod.ffn(p["ffn"], h2, cfg)
+        x = x + f_out
         cache2 = (kv2, st2)
     elif blk == "mlstm":
         out, cache2 = xlstm_mod.mlstm_decode(p["mlstm"], h, cache, cfg)
@@ -467,7 +472,8 @@ def decode_step_paged(params, tokens, caches, tables, pos, cfg, *,
     head = params.get("head")
     if head is None:
         head = params["embed"].T
-    logits = lm_logits(x, head)
+    with obs.part("lm_head"):
+        logits = lm_logits(x, head)
     logits = _cst(logits, "b s v", policy, mesh)
     return logits, list(new_caches)
 

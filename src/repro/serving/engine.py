@@ -15,11 +15,17 @@ and the per-step token vectors are simply accumulated); the host fetches
 everything once at drain, so the loop never forces a per-token sync.  Each
 prefill and decode step also leaves a device-side "all logits finite" flag,
 reduced once at drain into ``ServeMetrics.logits_finite``.
+An admission phase that admits a request, each request's admission and its
+first-token sync are host spans (``repro.obs.span``: ``serve.admit``,
+``serve.admit_request``, ``serve.first_token``) in any profiler trace taken
+meanwhile; the profiler's Python tracer already shows the decode phase and
+the drain as the calls ``_decode_phase`` and ``_drain``.
 Length-based eviction is the default; passing ``eos_id`` enables early
 exit at the cost of one host sync per step (documented, opt-in).
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -28,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as tf
 from repro.serving.buckets import BucketRegistry
@@ -39,7 +46,8 @@ class Request:
     rid: int
     prompt: np.ndarray            # (prompt_len,) int32
     max_new: int
-    submit_t: float = 0.0
+    submit_t: float = 0.0         # perf_counter stamps
+    admit_t: float = 0.0          # its admission started
     ttft_s: float | None = None   # submit -> first token (prefill argmax)
     slot: int = -1
     blocks: list[int] = field(default_factory=list)
@@ -55,22 +63,17 @@ class Request:
 
 @dataclass
 class ServeMetrics:
-    """Serving-tier observability: queue depth and batch occupancy are
-    sampled once per decode step; TTFT once per request."""
+    """Serving-tier observability: batch occupancy is sampled once per
+    decode step; queue wait (submit -> admission started) and TTFT once per
+    request, on the ``perf_counter`` clock."""
 
-    queue_depth: list[int] = field(default_factory=list)
     occupancy: list[float] = field(default_factory=list)
+    queue_s: dict[int, float] = field(default_factory=dict)
     ttft_s: dict[int, float] = field(default_factory=dict)
     prefills: int = 0
     decode_steps: int = 0
     tokens_generated: int = 0
-    t_total_s: float = 0.0
-    t_prefill_s: float = 0.0
     logits_finite: bool = True    # every prefill/decode logit was finite
-
-    @property
-    def tok_per_s(self) -> float:
-        return self.tokens_generated / max(self.t_total_s, 1e-9)
 
     @property
     def mean_occupancy(self) -> float:
@@ -81,13 +84,7 @@ class ServeMetrics:
             "prefills": self.prefills,
             "decode_steps": self.decode_steps,
             "tokens_generated": self.tokens_generated,
-            "tok_per_s": self.tok_per_s,
             "mean_occupancy": self.mean_occupancy,
-            "max_queue_depth": max(self.queue_depth, default=0),
-            "mean_ttft_s": (float(np.mean(list(self.ttft_s.values())))
-                            if self.ttft_s else 0.0),
-            "t_total_s": self.t_total_s,
-            "t_prefill_s": self.t_prefill_s,
             "logits_finite": self.logits_finite,
         }
 
@@ -169,13 +166,12 @@ class ServingEngine:
         rid = self._next_rid
         self._next_rid += 1
         req = Request(rid=rid, prompt=prompt, max_new=max_new,
-                      submit_t=time.time())
+                      submit_t=time.perf_counter())
         self._queue.append(req)
         return rid
 
     def run(self) -> tuple[dict[int, np.ndarray], ServeMetrics]:
         """Drain the queue; returns ({rid: (n_tokens,) int32}, metrics)."""
-        t0 = time.time()
         while self._queue or any(s is not None for s in self.slots):
             admitted = self._admit_phase()
             active = [s for s in self.slots if s is not None]
@@ -185,30 +181,51 @@ class ServingEngine:
                         "admission deadlock: empty batch but queued request "
                         "cannot get blocks — pool too small for one request")
                 continue
-            self.metrics.queue_depth.append(len(self._queue))
             self.metrics.occupancy.append(len(active) / self.batch)
             self._decode_phase()
         results = self._drain()
-        self.metrics.t_total_s += time.time() - t0
         return results, self.metrics
+
+    def op_scopes(self) -> dict[str, str]:
+        """``{instruction name: part}`` of the compiled decode step
+        (``obs.op_scopes``), lowered for the live arguments, so it is the
+        program a decode phase runs now.  It compiles that program again
+        (``obs.compiled_text``): call it outside any timed window.  Raises
+        if no instruction carries a part, as a program compiled without its
+        scopes (one served from a stale cache entry) would read."""
+        table = obs.op_scopes(obs.compiled_text(self._decode.lower(
+            self.params, self.tokens, self.caches, jnp.asarray(self.tables),
+            jnp.asarray(self.pos))))
+        if set(table.values()) <= {obs.CARRY}:
+            raise RuntimeError(
+                "the compiled decode step carries no part of obs.PARTS in "
+                "its metadata: its scopes were lost, so no split by part")
+        return table
 
     # -- loop phases ----------------------------------------------------------
 
     def _admit_phase(self) -> int:
         admitted = 0
-        while self._queue and None in self.slots:
-            req = self._queue[0]
-            blocks = self.alloc.alloc(
-                self.alloc.blocks_for(len(req.prompt) + req.max_new))
-            if blocks is None:
-                break
-            self._queue.popleft()
-            self._prefill_into(req, self.slots.index(None), blocks)
-            admitted += 1
+        # serve.admit opens once the first request has its blocks, so it
+        # spans only phases that admit
+        with contextlib.ExitStack() as phase:
+            while self._queue and None in self.slots:
+                req = self._queue[0]
+                blocks = self.alloc.alloc(
+                    self.alloc.blocks_for(len(req.prompt) + req.max_new))
+                if blocks is None:
+                    break
+                if not admitted:
+                    phase.enter_context(obs.span("serve.admit"))
+                self._queue.popleft()
+                with obs.span("serve.admit_request", rid=req.rid):
+                    self._prefill_into(req, self.slots.index(None), blocks)
+                admitted += 1
         return admitted
 
     def _prefill_into(self, req: Request, slot: int, blocks: list[int]):
-        t0 = time.time()
+        req.admit_t = time.perf_counter()
+        self.metrics.queue_s[req.rid] = req.admit_t - req.submit_t
         plen = len(req.prompt)
         ent = self.registry.prefill(plen)
         bl = ent.key[2]
@@ -220,8 +237,9 @@ class ServingEngine:
         self._finite_log.append(jnp.all(jnp.isfinite(logits)))
         # TTFT is defined at the first token's availability: sync here (one
         # per request, not per step)
-        req.first_tok = int(jax.device_get(tok0)[0])
-        req.ttft_s = time.time() - req.submit_t
+        with obs.span("serve.first_token", rid=req.rid):
+            req.first_tok = int(jax.device_get(tok0)[0])
+        req.ttft_s = time.perf_counter() - req.submit_t
         self.metrics.ttft_s[req.rid] = req.ttft_s
         self.metrics.prefills += 1
 
@@ -235,7 +253,6 @@ class ServingEngine:
         req.slot, req.blocks = slot, blocks
         req.step_start = len(self._step_log)
         self.slots[slot] = req
-        self.metrics.t_prefill_s += time.time() - t0
         if req.max_new == 1:
             self._evict(req)
 

@@ -226,6 +226,25 @@ def test_engine_matches_sequential_serve_bitwise():
         np.testing.assert_array_equal(results[rid], gen[0])
 
 
+def test_engine_stamps_queue_wait_before_first_token():
+    """One slot, three requests: each waits in the queue for the one before
+    it, and its queue wait (submit -> admission started) lies inside its
+    time to first token."""
+    cfg = reduced(get_config("hymba-1.5b"))
+    eng = ServingEngine(cfg, batch=1, max_seq=24, block=8,
+                        params=tf.init_params(cfg, jax.random.PRNGKey(0)))
+    rng = np.random.default_rng(0)
+    rids = [eng.submit(rng.integers(0, cfg.vocab, size=(L,)), 3)
+            for L in (5, 9, 7)]
+    _, metrics = eng.run()
+    assert sorted(metrics.queue_s) == sorted(metrics.ttft_s) == rids
+    for rid in rids:
+        assert 0.0 <= metrics.queue_s[rid] <= metrics.ttft_s[rid]
+    waits = [metrics.queue_s[rid] for rid in rids]
+    assert waits == sorted(waits) and waits[0] < waits[1]
+    assert metrics.summary()["logits_finite"]
+
+
 def test_engine_rejects_oversized_request_and_detects_deadlock():
     cfg = reduced(get_config("llama-7b"))
     eng = ServingEngine(cfg, batch=2, max_seq=16, block=8,
