@@ -134,22 +134,27 @@ def init_paged_kv_cache(cfg, n_blocks: int, block: int, dtype) -> PagedKVCache:
     return PagedKVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
-def attention_decode_paged(p: dict, x: jnp.ndarray, pool: PagedKVCache,
-                           tables: jnp.ndarray, pos: jnp.ndarray,
+def attention_decode_paged(p: dict, x: jnp.ndarray, pools: PagedKVCache,
+                           layer, tables: jnp.ndarray, pos: jnp.ndarray,
                            cfg) -> tuple[jnp.ndarray, PagedKVCache]:
-    """One decode step against a paged block pool.
+    """One decode step of layer ``layer`` against its paged block pool.
 
-    x: (b, 1, d_model); tables: (b, W) int32 block tables; pos: (b,) int32
-    per-slot absolute positions — unlike ``attention_decode``, every batch
-    slot sits at its *own* position (continuous batching).  This step's
-    K/V are scattered into block ``tables[b, pos//block]`` at row offset
-    ``pos % block``; the time-ordered cache view is gathered through the
-    same block-table lookup the planner prices (``ops.kv_block_gather``)
-    and attended with per-row validity masks (``idx <= pos``, plus the
-    sliding window on absolute positions for windowed archs — the pool is
-    time-ordered, so no ring reconstruction is needed).
+    ``pools`` holds every layer's pool stacked, (L, n_blocks, block, k, d)
+    leaves, and is updated in place.  x: (b, 1, d_model); tables: (b, W)
+    int32 block tables; pos: (b,) int32 per-slot absolute positions —
+    unlike ``attention_decode``, every batch slot sits at its *own*
+    position (continuous batching).  This step's K/V row of slot ``s`` is
+    written into ``pools[layer]`` at block ``tables[s, pos//block]``, row
+    offset ``pos % block`` (block ids come from the allocator, so they are
+    in range); the time-ordered cache view is gathered from the layer's
+    pool through the same block-table lookup the planner prices
+    (``ops.kv_block_gather``) and attended with per-row validity masks
+    (``idx <= pos``, plus the sliding window on absolute positions for
+    windowed archs — the pool is time-ordered, so no ring reconstruction
+    is needed).
     """
-    blk = pool.k.shape[1]
+    b = x.shape[0]
+    blk = pools.k.shape[2]
     W = tables.shape[1]
     # obs.part names the step's parts (repro.obs.PARTS) in the compiled
     # program's metadata, so a device trace can be split by part
@@ -159,13 +164,25 @@ def attention_decode_paged(p: dict, x: jnp.ndarray, pool: PagedKVCache,
         blk_ids = jnp.take_along_axis(tables, (pos // blk)[:, None],
                                       axis=1)[:, 0]
         off = pos % blk
-        # slots own disjoint blocks (block 0 = shared scratch for idle slots)
-        k_pool = pool.k.at[blk_ids, off].set(k_new[:, 0])
-        v_pool = pool.v.at[blk_ids, off].set(v_new[:, 0])
+        # one row per slot (block 0 = shared scratch for idle slots).  An
+        # update-slice writes in place whatever layout the pool is stored
+        # in; a scatter makes the TPU compiler copy the stack into the
+        # layout its scatter wants
+        k_pools, v_pools = pools
+        for s in range(b):
+            at = (layer, blk_ids[s], off[s], 0, 0)
+            k_pools = jax.lax.dynamic_update_slice(
+                k_pools, k_new[s][None, None].astype(k_pools.dtype), at)
+            v_pools = jax.lax.dynamic_update_slice(
+                v_pools, v_new[s][None, None].astype(v_pools.dtype), at)
 
     with obs.part("kv_gather"):
-        kh = ops.kv_block_gather(k_pool, tables, W * blk)   # (b, kv, t, d)
-        vh = ops.kv_block_gather(v_pool, tables, W * blk)
+        kh = ops.kv_block_gather(
+            jax.lax.dynamic_index_in_dim(k_pools, layer, keepdims=False),
+            tables, W * blk)                                # (b, kv, t, d)
+        vh = ops.kv_block_gather(
+            jax.lax.dynamic_index_in_dim(v_pools, layer, keepdims=False),
+            tables, W * blk)
     qh = q.transpose(0, 2, 1, 3)                        # (b, h, 1, hd)
 
     with obs.part("attend"):
@@ -177,7 +194,7 @@ def attention_decode_paged(p: dict, x: jnp.ndarray, pool: PagedKVCache,
     with obs.part("out_proj"):
         o = o.transpose(0, 2, 1, 3)
         out = jnp.einsum("bshd,hda->bsa", o, p["wo"])
-    return out, PagedKVCache(k_pool, v_pool)
+    return out, PagedKVCache(k_pools, v_pools)
 
 
 def _decode_attend(q, k, v, valid, cfg):

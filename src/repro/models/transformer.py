@@ -17,6 +17,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro import obs
 from repro.models import attention as attn_mod
@@ -405,12 +406,43 @@ def init_paged_caches(cfg, batch: int, n_blocks: int, block: int, *,
     return build()
 
 
-def _block_decode_paged(blk: str, p: dict, x, cache, tables, pos, cfg,
+def _stored_layout(caches, device):
+    """Pin each carried cache leaf to the layout ``device`` stores it in.
+
+    Left free, the compiler gives the loop carry the layout its readers
+    prefer and copies the whole stack into it and back around the loop:
+    the TPU stores a bf16 (L, n, 16, 5, 64) pool blocks-minor, while its
+    gather wants blocks-major.  Pinned, only the layer that is read is
+    converted."""
+    def pin(x):
+        layout = device.client.get_default_layout(x.dtype, x.shape, device)
+        return with_layout_constraint(x, Layout.from_pjrt_layout(layout))
+
+    return jax.tree.map(pin, caches)
+
+
+def _layer_of(states, l):
+    return jax.tree.map(
+        lambda s: jax.lax.dynamic_index_in_dim(s, l, keepdims=False), states)
+
+
+def _set_layer(states, l, new):
+    return jax.tree.map(
+        lambda s, n: jax.lax.dynamic_update_index_in_dim(s, n, l, 0),
+        states, new)
+
+
+def _block_decode_paged(blk: str, p: dict, x, caches, l, tables, pos, cfg,
                         policy, mesh):
+    """Layer ``l`` of one pattern position.  ``caches`` is the position's
+    stacked (units, ...) cache; the block reads its own layer of it and
+    writes that layer back in place, so the stack can be the loop's carry:
+    the KV pool through ``attention_decode_paged``, a recurrent state by
+    slicing layer ``l`` out and updating it there."""
     h = rmsnorm(x, p["norm1"], cfg.norm_eps)
     if blk == "attn":
         a_out, cache2 = attn_mod.attention_decode_paged(
-            p["attn"], h, cache, tables, pos, cfg)
+            p["attn"], h, caches, l, tables, pos, cfg)
         x = x + a_out
         h2 = rmsnorm(x, p["norm2"], cfg.norm_eps)
         with obs.part("ffn"):
@@ -421,11 +453,12 @@ def _block_decode_paged(blk: str, p: dict, x, cache, tables, pos, cfg,
                 m_out = ffn_mod.ffn(p["ffn"], h2, cfg)
         x = x + m_out
     elif blk == "hymba":
-        kv, st = cache
+        kv, st = caches
         a_out, kv2 = attn_mod.attention_decode_paged(
-            p["attn"], h, kv, tables, pos, cfg)
+            p["attn"], h, kv, l, tables, pos, cfg)
         with obs.part("ssm"):
-            s_out, st2 = ssm_mod.ssm_decode(p["ssm"], h, st, cfg)
+            s_out, st2 = ssm_mod.ssm_decode(p["ssm"], h, _layer_of(st, l),
+                                            cfg)
         mixed = 0.5 * (rmsnorm(a_out, p["norm_a"], cfg.norm_eps)
                        + rmsnorm(s_out, p["norm_s"], cfg.norm_eps))
         x = x + mixed
@@ -433,13 +466,17 @@ def _block_decode_paged(blk: str, p: dict, x, cache, tables, pos, cfg,
         with obs.part("ffn"):
             f_out = ffn_mod.ffn(p["ffn"], h2, cfg)
         x = x + f_out
-        cache2 = (kv2, st2)
+        cache2 = (kv2, _set_layer(st, l, st2))
     elif blk == "mlstm":
-        out, cache2 = xlstm_mod.mlstm_decode(p["mlstm"], h, cache, cfg)
+        out, st2 = xlstm_mod.mlstm_decode(p["mlstm"], h, _layer_of(caches, l),
+                                          cfg)
         x = x + out
+        cache2 = _set_layer(caches, l, st2)
     elif blk == "slstm":
-        out, cache2 = xlstm_mod.slstm_decode(p["slstm"], h, cache, cfg)
+        out, st2 = xlstm_mod.slstm_decode(p["slstm"], h, _layer_of(caches, l),
+                                          cfg)
         x = x + out
+        cache2 = _set_layer(caches, l, st2)
     else:
         raise ValueError(blk)
     return x, cache2
@@ -450,23 +487,32 @@ def decode_step_paged(params, tokens, caches, tables, pos, cfg, *,
     """One continuous-batching decode step.  tokens (b, 1); tables (b, W)
     int32 block tables; pos (b,) int32 per-slot positions.  Returns
     (logits (b, 1, v), new caches).  Idle slots point their table rows at
-    the scratch block 0 and carry pos such that their writes land there."""
+    the scratch block 0 and carry pos such that their writes land there.
+
+    The stacked caches are the layer scan's carry, not its scanned input
+    and output: each unit updates its own layer of them in place, so a
+    step moves this step's rows and never a whole pool (with the caches
+    donated, the output aliases them)."""
     x = embed(params["embed"], tokens).astype(dtype_of(cfg))
     x = _cst(x, "b s a", policy, mesh)
     pattern = cfg.block_pattern
+    units = cfg.n_layers // len(pattern)
+    device = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
 
-    def unit(x, scanned):
-        unit_params, unit_caches = scanned
+    def unit(carry, scanned):
+        x, caches = carry
+        unit_params, l = scanned
         new_caches = []
         for ppos, blk in enumerate(pattern):
             x, c2 = _block_decode_paged(
-                blk, unit_params[ppos], x, unit_caches[ppos], tables, pos,
+                blk, unit_params[ppos], x, caches[ppos], l, tables, pos,
                 cfg, policy, mesh)
             new_caches.append(c2)
-        return x, tuple(new_caches)
+        return (x, _stored_layout(tuple(new_caches), device)), None
 
-    x, new_caches = jax.lax.scan(
-        unit, x, (tuple(params["layers"]), tuple(caches)),
+    (x, new_caches), _ = jax.lax.scan(
+        unit, (x, tuple(caches)),
+        (tuple(params["layers"]), jnp.arange(units, dtype=jnp.int32)),
         unroll=True if unroll else 1)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     head = params.get("head")

@@ -200,6 +200,123 @@ def test_forward_logit_index_matches_exact_prefill_bitwise():
 
 
 # ---------------------------------------------------------------------------
+# paged decode step: the stacked caches updated in place == layer by layer
+# ---------------------------------------------------------------------------
+
+
+def _paged_attention_one_layer(p, x, pool, tables, pos, cfg):
+    """One layer's paged attention on its own (n, block, k, d) pool."""
+    from repro.models.attention import _decode_attend, _project_qkv
+
+    blk, W = pool.k.shape[1], tables.shape[1]
+    q, k_new, v_new = _project_qkv(p, x, cfg, pos[:, None])
+    blk_ids = jnp.take_along_axis(tables, (pos // blk)[:, None], axis=1)[:, 0]
+    off = pos % blk
+    k_pool = pool.k.at[blk_ids, off].set(k_new[:, 0])
+    v_pool = pool.v.at[blk_ids, off].set(v_new[:, 0])
+    kh = ops.kv_block_gather(k_pool, tables, W * blk)
+    vh = ops.kv_block_gather(v_pool, tables, W * blk)
+    idx = jnp.arange(W * blk)
+    valid = idx[None, :] <= pos[:, None]
+    if cfg.window:
+        valid &= idx[None, :] > (pos[:, None] - cfg.window)
+    o = _decode_attend(q.transpose(0, 2, 1, 3), kh, vh, valid, cfg)
+    out = jnp.einsum("bshd,hda->bsa", o.transpose(0, 2, 1, 3), p["wo"])
+    return out, type(pool)(k_pool, v_pool)
+
+
+def _decode_step_paged_layer_by_layer(params, tokens, caches, tables, pos,
+                                      cfg):
+    """The paged decode step as a plain loop over layers: each layer's
+    cache is sliced out of the stack, updated, and the updated layers are
+    stacked again."""
+    from repro.models import ffn as ffn_mod
+    from repro.models import moe as moe_mod
+    from repro.models import ssm as ssm_mod
+    from repro.models import xlstm as xlstm_mod
+    from repro.models.common import dtype_of, embed, lm_logits, rmsnorm
+
+    def layer(tree, l):
+        return jax.tree.map(lambda a: a[l], tree)
+
+    x = embed(params["embed"], tokens).astype(dtype_of(cfg))
+    units = cfg.n_layers // len(cfg.block_pattern)
+    new = [[] for _ in cfg.block_pattern]
+    for l in range(units):
+        for i, blk in enumerate(cfg.block_pattern):
+            p, c = layer(params["layers"][i], l), layer(caches[i], l)
+            h = rmsnorm(x, p["norm1"], cfg.norm_eps)
+            if blk in ("attn", "hymba"):
+                pool = c if blk == "attn" else c[0]
+                a_out, pool2 = _paged_attention_one_layer(
+                    p["attn"], h, pool, tables, pos, cfg)
+            if blk == "attn":
+                x = x + a_out
+                h2 = rmsnorm(x, p["norm2"], cfg.norm_eps)
+                x = x + (moe_mod.moe_ffn(p["moe"], h2, cfg)[0] if cfg.moe
+                         else ffn_mod.ffn(p["ffn"], h2, cfg))
+                c2 = pool2
+            elif blk == "hymba":
+                s_out, st2 = ssm_mod.ssm_decode(p["ssm"], h, c[1], cfg)
+                x = x + 0.5 * (rmsnorm(a_out, p["norm_a"], cfg.norm_eps)
+                               + rmsnorm(s_out, p["norm_s"], cfg.norm_eps))
+                h2 = rmsnorm(x, p["norm2"], cfg.norm_eps)
+                x = x + ffn_mod.ffn(p["ffn"], h2, cfg)
+                c2 = (pool2, st2)
+            else:
+                decode = (xlstm_mod.mlstm_decode if blk == "mlstm"
+                          else xlstm_mod.slstm_decode)
+                out, c2 = decode(p[blk], h, c, cfg)
+                x = x + out
+            new[i].append(c2)
+    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    head = params.get("head")
+    logits = lm_logits(x, params["embed"].T if head is None else head)
+    return logits, [jax.tree.map(lambda *ls: jnp.stack(ls), *n) for n in new]
+
+
+@pytest.mark.parametrize("arch", ["llama-7b", "hymba-1.5b", "xlstm-125m",
+                                  "mixtral-8x7b"],
+                         ids=["attn", "hymba", "mlstm+slstm", "attn-moe"])
+def test_paged_decode_step_matches_layer_by_layer_bitwise(arch):
+    """Several decode steps with the stacked caches carried through the
+    layer scan and updated in place give the logits and every cache leaf,
+    bit for bit, of the layer-by-layer step.  Slots sit at distinct
+    positions, slot 3 idles on scratch block 0, and slot 0 crosses from
+    its first block into its second."""
+    cfg = reduced(get_config(arch))
+    params = tf.init_params(cfg, jax.random.PRNGKey(0))
+    b, blk, W = 4, 4, 3
+    caches = tf.init_paged_caches(cfg, b, 1 + b * W, blk)
+    leaves, tree = jax.tree.flatten(caches)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+    caches = jax.tree.unflatten(tree, [            # every row holds data
+        jax.random.normal(k, a.shape, a.dtype) for k, a in zip(keys, leaves)])
+    tables = jnp.array([[1, 2, 3], [4, 5, 6], [7, 8, 9], [0, 0, 0]],
+                       jnp.int32)
+    pos = jnp.array([2, 6, 9, 0], jnp.int32)
+    advance = jnp.array([1, 1, 1, 0], jnp.int32)
+    tokens = jnp.array([[5], [7], [11], [0]], jnp.int32)
+
+    step = jax.jit(lambda *a: tf.decode_step_paged(*a, cfg))
+    ref_step = jax.jit(lambda *a: _decode_step_paged_layer_by_layer(*a, cfg))
+    ref_caches = caches
+    for _ in range(3):
+        logits, caches = step(params, tokens, caches, tables, pos)
+        ref_logits, ref_caches = ref_step(params, tokens, ref_caches, tables,
+                                          pos)
+        np.testing.assert_array_equal(np.asarray(logits),
+                                      np.asarray(ref_logits))
+        for got, want in zip(jax.tree.leaves(caches),
+                             jax.tree.leaves(ref_caches)):
+            assert got.dtype == want.dtype and got.shape == want.shape
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        tokens = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
+        pos = pos + advance
+    assert int(pos[0]) == 5                       # 2, 3 | 4, 5: a new block
+
+
+# ---------------------------------------------------------------------------
 # the engine: continuous batching == sequential serve(), bit for bit
 # ---------------------------------------------------------------------------
 

@@ -1,22 +1,27 @@
-"""The Pallas kernels compile for a TPU v5e at the widths the main path uses.
+"""The Pallas kernels compile for a TPU v5e at the widths the main path uses,
+and the paged decode step compiles there without copying its KV pool.
 
 Nothing runs here: each kernel is lowered and compiled for a described
 (not attached) v5e chip, with ``interpret=False``, and the compiled program
 must hold the Mosaic kernel (``tpu_custom_call``).  This catches what
 interpret mode cannot — block shapes the TPU refuses, unaligned slices,
-too much VMEM — without a chip.
+too much VMEM — without a chip.  The decode step's compiled text is read
+for the copies the TPU compiler puts around the pool.
 
 The topology is described inside a module-scoped fixture (never at import,
 in ``parametrize`` or in ``skipif``), so every xdist worker collects the
 same tests and only the worker that runs this file loads the TPU compiler.
 Keep every such compile in this one file.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.kernels.flash_attention import flash_attention, flash_attention_step
 from repro.kernels.matmul import matmul
@@ -103,3 +108,73 @@ def test_gmm_compiles(one_chip, e, c, k, n):
     txt = _compile_text(lambda x, w: gmm(x, w, interpret=False),
                         [((e, c, k), BF16), ((e, k, n), BF16)], one_chip)
     assert "tpu_custom_call" in txt
+
+
+_INST = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+                   r"([a-z][a-z\-]*)\(([^)]*)\)")
+
+
+def _paged_decode_compiled(one_chip, n_layers):
+    """The jitted paged decode step of hymba-1.5b at its widths, 64 slots,
+    ``max_seq`` 1536, block 16, caches donated, compiled for the chip."""
+    from repro.configs import get_config
+    from repro.launch import steps
+    from repro.models import transformer as tf
+
+    cfg = dataclasses.replace(get_config("hymba-1.5b"), n_layers=n_layers)
+    b, block, W = 64, 16, 1536 // 16
+    n_blocks = 1 + b * W
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    mesh = Mesh(np.array(list(one_chip.device_set)), ("x",))
+    step = jax.jit(steps.make_paged_serve_step(cfg, mesh=mesh),
+                   donate_argnums=(2,))
+    params = sds(tf.init_params(cfg, abstract=True))
+    caches = sds(tf.init_paged_caches(cfg, b, n_blocks, block, abstract=True))
+    compiled = step.lower(
+        params, sds(jax.ShapeDtypeStruct((b, 1), jnp.int32)), caches,
+        sds(jax.ShapeDtypeStruct((b, W), jnp.int32)),
+        sds(jax.ShapeDtypeStruct((b,), jnp.int32))).compile()
+    n_params = len(jax.tree.leaves(params))
+    cache_args = set(range(n_params + 1,
+                           n_params + 1 + len(jax.tree.leaves(caches))))
+    return compiled, (n_blocks, block, 5, 64), cache_args
+
+
+def test_paged_decode_step_updates_the_pool_in_place(one_chip):
+    """The stacked pool is the layer scan's carry and is written in place:
+    no instruction copies or slices the stack, each write into it is one
+    slot's row, the only layer-sized reads are the gather's (the TPU keeps
+    the pool blocks-minor, its gather wants blocks-major), the donated
+    caches alias the outputs, and the temporaries hold less than the
+    stacked pools and do not grow with the layers."""
+    temps = {}
+    for n_layers in (2, 4):
+        compiled, pool, cache_args = _paged_decode_compiled(one_chip,
+                                                            n_layers)
+        txt = compiled.as_text()
+        insts = {m.group(1): m for m in map(_INST.match, txt.splitlines())
+                 if m}
+        stack = (n_layers, *pool)
+        for name, m in insts.items():
+            dims = tuple(int(d) for d in m.group(3).split(",") if d)
+            op = m.group(4)
+            line = m.string
+            if dims == stack and op in ("copy", "dynamic-slice", "fusion",
+                                        "dynamic-update-slice"):
+                assert op == "dynamic-update-slice", line[:200]
+                update = insts[m.group(5).split(",")[1].strip().lstrip("%")]
+                assert update.group(3) == "1,1,1,5,64", line[:200]
+            if dims in (pool, (1, *pool)) and op not in (
+                    "parameter", "bitcast", "get-tuple-element"):
+                assert "/kv_gather/" in line, line[:200]
+        aliased = {int(a) for a in re.findall(
+            r"\{\d+\}: \((\d+), \{\}, may-alias\)", txt.splitlines()[0])}
+        assert cache_args <= aliased
+        temps[n_layers] = compiled.memory_analysis().temp_size_in_bytes
+    layer_pool = int(np.prod(pool)) * 2                   # bf16, 62.9 MB
+    assert temps[4] < 2 * 4 * layer_pool                  # both stacks
+    assert temps[4] - temps[2] < layer_pool
