@@ -11,6 +11,7 @@ import time
 from dataclasses import dataclass, field
 from pathlib import Path
 
+from chipbench import trace
 from chipbench.spec import ROOT
 
 PEAKS_FILE = ROOT / "chipbench" / "peaks.json"
@@ -156,8 +157,12 @@ class Tracer:
 
     ``start_in(delay, length)`` traces ``length`` seconds starting
     ``delay`` seconds from now, from a timer thread, while the caller's
-    thread runs on; ``join()`` waits for it.  ``t0``/``t1`` are the slice's
-    ends on the perf_counter clock."""
+    thread runs on; ``join()`` waits for it.  The profiling session runs
+    on before and after the slice (starting and stopping it takes a while),
+    so the slice is marked in the trace by the annotation ``trace.SLICE``,
+    over which ``trace.reduce`` reads busy time, gaps and the window.
+    ``t0``/``t1`` are the slice's ends on the perf_counter clock, taken
+    inside the annotation."""
 
     def __init__(self, enabled: bool, tag: str):
         self.enabled = enabled
@@ -178,9 +183,10 @@ class Tracer:
             shutil.rmtree(self.dir, ignore_errors=True)
             self.dir.mkdir(parents=True)
             jax.profiler.start_trace(str(self.dir))
-            self.t0 = time.perf_counter()
-            time.sleep(length)
-            self.t1 = time.perf_counter()
+            with jax.profiler.TraceAnnotation(trace.SLICE):
+                self.t0 = time.perf_counter()
+                time.sleep(length)
+                self.t1 = time.perf_counter()
             jax.profiler.stop_trace()
 
         th = threading.Thread(target=run, daemon=True)
@@ -190,10 +196,6 @@ class Tracer:
     def join(self) -> None:
         for th in self._threads:
             th.join()
-
-    @property
-    def window_s(self) -> float:
-        return self.t1 - self.t0
 
     def xplane(self) -> Path:
         found = sorted(self.dir.glob("**/*.xplane.pb"))

@@ -1,7 +1,7 @@
-"""The share of the traced window in which no operation ran on the device,
-averaged over the chips."""
+"""The share of the traced slice in which no operation ran on the device,
+averaged over the chips: busy time and the slice are read over the same
+slice of the trace."""
 
 
 def read(ctx):
-    busy = ctx["trace"].busy_s
-    return 100.0 * (1.0 - busy / ctx["window_s"]) if busy else None
+    return 100.0 * (1.0 - ctx["trace"].busy_s / ctx["window_s"])

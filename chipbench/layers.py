@@ -7,8 +7,11 @@ from chipbench import harness, spec, trace
 
 def read_all(cell, tracer: harness.Tracer, counters: dict,
              device: dict) -> tuple[dict, dict, dict]:
-    """(metrics, device with busy_s and window_s, breakdown)."""
-    summary = trace.reduce(tracer.xplane())
+    """(metrics, device with busy_s and window_s, breakdown).  Busy time
+    and the window are two readings of the traced slice, on the chips the
+    cell runs on."""
+    chips = [d.id for d in harness.chips(cell.chips)]
+    summary = trace.reduce(tracer.xplane(), chips=chips)
     tracer.cleanup()
     ctx = {
         "cell": cell.name,
@@ -17,7 +20,7 @@ def read_all(cell, tracer: harness.Tracer, counters: dict,
         "chips": cell.chips,
         "peak": harness.peaks(device["kind"]),
         "trace": summary,
-        "window_s": tracer.window_s,
+        "window_s": summary.window_s,
         "counters": counters,
     }
     metrics = {}
@@ -25,5 +28,5 @@ def read_all(cell, tracer: harness.Tracer, counters: dict,
         value = spec.load_metric_reader(m["name"]).read(ctx)
         if value is not None:
             metrics[m["name"]] = float(value)
-    device = dict(device, busy_s=summary.busy_s, window_s=tracer.window_s)
+    device = dict(device, busy_s=summary.busy_s, window_s=summary.window_s)
     return metrics, device, summary.breakdown()

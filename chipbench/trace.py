@@ -2,19 +2,24 @@
 
 The trace holds one plane per device (``/device:TPU:<i>``) with a line of
 compiled programs (``XLA Modules``) and a line of the operations they ran
-(``XLA Ops``), and host planes whose lines are threads.  The reduction keeps
-per device:
+(``XLA Ops``), and host planes whose lines are threads.  The profiling
+session runs longer than the slice it was started for, so the slice is
+marked by a host annotation (``SLICE``, opened by ``harness.Tracer`` once
+the session has started and closed before it stops), and each device's
+clock is moved onto the host's by pairing program launches with program
+starts.  The reduction keeps per device:
 
-* busy time: the union of the intervals in which an operation ran;
+* busy time: the union of the intervals in which an operation ran, cut to
+  the slice, so that it never exceeds the slice's length (``window_s``);
 * time and count per program, matched by a part of its name
-  (``jit_decode_step``), and per operation, matched likewise;
-* the idle gaps between busy intervals, each named by the latest-started
-  event of the host's Python thread that spans its middle (what the host
-  was doing meanwhile), the device clock moved onto the host's by pairing
-  program launches with program starts.
+  (``jit_decode_step``), and per operation, matched likewise, over the
+  whole trace;
+* the idle gaps inside the slice, each named by the latest-started host
+  event that spans its middle (what the host was doing meanwhile).
 
-Every number is averaged over the devices in the trace.  Nothing here
-knows a cell: readers in ``layer_metrics/`` ask for what they need.
+Every number is averaged over the devices of the chips a cell runs on.
+Nothing here knows a cell: readers in ``layer_metrics/`` ask for what they
+need.
 """
 from __future__ import annotations
 
@@ -28,6 +33,7 @@ COLLECTIVE = re.compile(
     r"^(all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)"
     r"(-start|-done)?$")
 CONTAINERS = {"while", "conditional", "call"}
+SLICE = "chipbench.slice"         # the host annotation around the slice
 _SUFFIX = re.compile(r"(\.\d+)+$|\(\d+\)$")
 _INST = re.compile(r"^%?([A-Za-z_][\w\-]*?)(?:\.\d+)*\s*=")
 _OPCODE = re.compile(r"\s([a-z][a-z\-]*)\(")
@@ -73,10 +79,23 @@ def _length(intervals) -> int:
     return sum(e - s for s, e in intervals)
 
 
+def _clip(intervals, lo: int, hi: int) -> list[tuple[int, int]]:
+    """Sorted, disjoint intervals cut to ``[lo, hi]``."""
+    return [(max(s, lo), min(e, hi)) for s, e in intervals
+            if e > lo and s < hi]
+
+
+def _idle(busy, lo: int, hi: int) -> list[tuple[int, int]]:
+    """The parts of ``[lo, hi]`` that sorted, disjoint ``busy`` intervals
+    inside it leave uncovered."""
+    edges = [lo] + [t for iv in busy for t in iv] + [hi]
+    return [(s, e) for s, e in zip(edges[::2], edges[1::2]) if e > s]
+
+
 @dataclass
 class Device:
     name: str
-    busy: list = field(default_factory=list)          # merged op intervals
+    busy: list = field(default_factory=list)  # merged op intervals in the slice
     modules: dict = field(default_factory=dict)       # base name -> [ns, n]
     ops: dict = field(default_factory=dict)     # (module, op name) -> [ns, n]
     launches: list = field(default_factory=list)  # (start, end, program)
@@ -86,15 +105,21 @@ class Device:
 class TraceSummary:
     devices: list
     gaps: list          # (name of host activity, ns) on the first device
-    span_ns: int        # first to last device event
+    window_ns: int      # the slice's length
 
     @property
     def n(self) -> int:
-        return max(1, len(self.devices))
+        return len(self.devices)
 
     @property
     def busy_s(self) -> float:
+        """Seconds of the slice in which an operation ran, averaged over
+        the devices: at most ``window_s``."""
         return sum(_length(d.busy) for d in self.devices) / self.n / 1e9
+
+    @property
+    def window_s(self) -> float:
+        return self.window_ns / 1e9
 
     def module(self, part: str) -> tuple[float, float]:
         """(seconds, executions) of programs whose name holds ``part``."""
@@ -134,19 +159,44 @@ class TraceSummary:
         }
 
 
-def _device_planes(planes):
-    return sorted((p for p in planes if p.name.startswith("/device:TPU")),
-                  key=lambda p: p.name)
+def _device_planes(planes, chips) -> list:
+    """The planes of the chips with these device ids, in that order."""
+    by_name = {p.name: p for p in planes}
+    names = [f"/device:TPU:{i}" for i in chips]
+    missing = [n for n in names if n not in by_name]
+    if missing or not names:
+        found = sorted(n for n in by_name if n.startswith("/device:"))
+        raise ValueError(f"the trace has no plane for {missing or 'any chip'}"
+                         f"; its device planes: {found}")
+    return [by_name[n] for n in names]
+
+
+def _host_lines(planes) -> list:
+    return [ln for p in planes if p.name.startswith("/host:")
+            for ln in p.lines]
+
+
+def _slice(planes) -> tuple[int, int]:
+    """(start, end) of the ``SLICE`` annotation, on any host line, on the
+    host's trace clock."""
+    found = [(int(e.start_ns), int(e.start_ns + e.duration_ns))
+             for ln in _host_lines(planes) for e in ln.events
+             if e.name == SLICE]
+    if len(found) != 1:
+        raise ValueError(f"the trace holds {len(found)} {SLICE!r} "
+                         f"annotations, not one: it was not recorded by "
+                         f"harness.Tracer, and no slice can be measured")
+    return found[0]
 
 
 def _host_events(planes) -> list[tuple[int, int, str]]:
-    """Events of the Python thread (its functions and annotations) where
-    the trace has one, else of every host thread."""
-    lines = [ln for p in planes if p.name.startswith("/host:")
-             for ln in p.lines]
+    """Events of the Python threads (their functions and annotations)
+    where the trace has them, else of every host thread; the slice's own
+    annotation left out."""
+    lines = _host_lines(planes)
     py = [ln for ln in lines if ln.name == "python"]
     return [(int(e.start_ns), int(e.start_ns + e.duration_ns), e.name)
-            for ln in (py or lines) for e in ln.events]
+            for ln in (py or lines) for e in ln.events if e.name != SLICE]
 
 
 def _name_gaps(host: list, gaps: list) -> list[tuple[str, int]]:
@@ -168,18 +218,23 @@ def _name_gaps(host: list, gaps: list) -> list[tuple[str, int]]:
     return out
 
 
-def _clock_offset(planes, modules: list) -> int:
-    """Host time minus device time: the k-th program launched on the host
-    (``PJRT_LoadedExecutable_Execute``) is the k-th to start on the
-    device, a little after its launch returns; the median over the pairs."""
-    ends = sorted(int(e.start_ns + e.duration_ns) for p in planes
-                  if p.name.startswith("/host:") for ln in p.lines
+def _launch_ends(planes) -> list[int]:
+    return sorted(int(e.start_ns + e.duration_ns) for ln in _host_lines(planes)
                   for e in ln.events
                   if e.name == "PJRT_LoadedExecutable_Execute")
-    n = min(len(ends), len(modules))
+
+
+def _clock_offset(ends: list[int], dev: Device) -> int:
+    """Host time minus device time: the k-th program launched on the host
+    (``ends``: when each ``PJRT_LoadedExecutable_Execute`` returned) is the
+    k-th to start on the device, a little after its launch returns; the
+    median over the pairs."""
+    n = min(len(ends), len(dev.launches))
     if not n:
-        return 0
-    d = sorted(ends[i] - modules[i][0] for i in range(n))
+        raise ValueError(f"no program launch on the host pairs with a "
+                         f"program on {dev.name}: its clock cannot be "
+                         f"moved onto the host's")
+    d = sorted(ends[i] - dev.launches[i][0] for i in range(n))
     return d[n // 2]
 
 
@@ -225,24 +280,32 @@ def _reduce_device(p) -> Device:
     return dev
 
 
-def reduce(profile, *, min_gap_ns: int = 100_000) -> TraceSummary:
+def reduce(profile, *, chips, min_gap_ns: int = 100_000) -> TraceSummary:
     """Reduce a ``jax.profiler.ProfileData`` (or a path to an
-    ``.xplane.pb``) to a ``TraceSummary``."""
+    ``.xplane.pb``) recorded by ``harness.Tracer`` to a ``TraceSummary``
+    of the chips with device ids ``chips``.  Raises where the trace has no
+    slice, no plane of one of the chips, no launch to pair with a chip's
+    programs, or no operation in the slice."""
     if not hasattr(profile, "planes"):
         from jax.profiler import ProfileData
 
         profile = ProfileData.from_file(str(profile))
     planes = list(profile.planes)
-    devices = [_reduce_device(p) for p in _device_planes(planes)]
-    busy = [d.busy for d in devices if d.busy]
-    span = (max(b[-1][1] for b in busy) - min(b[0][0] for b in busy)
-            if busy else 0)
+    start, end = _slice(planes)
+    devices = [_reduce_device(p) for p in _device_planes(planes, chips)]
+    ends = _launch_ends(planes)
     gaps = []
-    if devices and devices[0].busy:
-        b = devices[0].busy
-        off = _clock_offset(planes, devices[0].launches)
-        gaps = _name_gaps(_host_events(planes),
-                          [((e0 + s1) // 2 + off, s1 - e0)
-                           for (_, e0), (s1, _) in zip(b, b[1:])
-                           if s1 - e0 >= min_gap_ns])
-    return TraceSummary(devices=devices, gaps=gaps, span_ns=span)
+    for k, dev in enumerate(devices):
+        off = _clock_offset(ends, dev)
+        lo, hi = start - off, end - off
+        dev.busy = _clip(dev.busy, lo, hi)
+        if k == 0:
+            gaps = _name_gaps(_host_events(planes),
+                              [((s + e) // 2 + off, e - s)
+                               for s, e in _idle(dev.busy, lo, hi)
+                               if e - s >= min_gap_ns])
+    summary = TraceSummary(devices=devices, gaps=gaps, window_ns=end - start)
+    if not summary.busy_s > 0:
+        raise ValueError("no operation ran on the chips inside the traced "
+                         "slice")
+    return summary
